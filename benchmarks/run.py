@@ -8,6 +8,8 @@ Output CSV: table,config,nfe,us_per_call,sw2,mode_recovery
 """
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import quality
 from . import tables
 from . import serving
@@ -28,6 +30,7 @@ ALL = {
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
     names = argv or list(ALL)
+    enable_compile_cache()
     print("table,config,nfe,us_per_call,sw2,mode_recovery")
     for n in names:
         for row in ALL[n]():

@@ -1,7 +1,9 @@
 """Paper config: CLD on CIFAR10-shaped data with the gDDIM R_t
-parameterization (paper Sec. 5, Tab. 1/4).  The score net is a DiT (the
-TPU-native analogue of the paper's 108M UNet — DESIGN.md §3); `reduced`
-gives the CPU-trainable smoke size."""
+parameterization (paper Sec. 5, Tab. 1/4).  The score net is a DiT
+(models/score_net.py), the transformer stand-in for the paper's 108M
+convolutional UNet: d_model 768, 24 layers, 12 heads, patch 2, bf16 —
+the width `chip_smoke.py` serves on a TPU.  `reduced` gives the
+CPU-trainable smoke size."""
 import jax.numpy as jnp
 
 from ..sde import CLD

@@ -116,7 +116,8 @@ def global_serve_mesh(data: Optional[int] = None, model: int = 1):
     if data * model > n:
         raise ValueError(f"mesh data={data} x model={model} needs "
                          f"{data * model} devices, {n} present globally")
-    return jax.make_mesh((data, model), ("data", "model"))
+    from ..launch.mesh import make_mesh
+    return make_mesh((data, model), ("data", "model"))
 
 
 def shard_requests(requests: Sequence[Any], num_processes: int,

@@ -23,7 +23,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
@@ -81,9 +80,9 @@ def pipeline_apply(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
+    fn = jax.shard_map(per_stage, mesh=mesh,
                    in_specs=(spec_params, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(stage_params, x_micro)
 
 

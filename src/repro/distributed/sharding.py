@@ -31,6 +31,7 @@ import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+from jax._src import mesh as _mesh_lib
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
@@ -319,19 +320,51 @@ def constrain_acts(x: Array) -> Array:
 def _ambient_mesh():
     """The mesh installed by `with mesh:` (None outside a mesh context).
 
-    jax 0.4.x has no public ambient-mesh accessor; try the semi-public
-    pxla location first, then the private module it re-exports.  If a JAX
-    upgrade breaks both, constrain_batch degrades to a no-op — that
-    regression is caught by test_distributed.py::test_fsdp_train_step_*,
-    which asserts sharded == single-device numerics."""
-    for get in (lambda: __import__("jax").interpreters.pxla.thread_resources,
-                lambda: __import__("jax._src.mesh", fromlist=["thread_resources"]).thread_resources):
-        try:
-            m = get().env.physical_mesh
-            return None if m.empty else m
-        except Exception:
-            continue
-    return None                                         # pragma: no cover
+    `with mesh:` records the mesh in `jax._src.mesh.thread_resources`, the
+    one place the installed JAX keeps it (the public `jax.sharding.get_mesh`
+    sees only `jax.set_mesh`).  A JAX that moves it fails here loudly
+    rather than turning constrain_batch into a silent no-op."""
+    m = _mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
+
+
+def per_batch_shard(fn, *args, replicated: Tuple[int, ...] = (),
+                    head_dim: Optional[int] = None):
+    """`fn(*args)`, run once per batch shard of the ambient mesh.
+
+    A Pallas kernel is an opaque custom call to XLA's SPMD partitioner:
+    handed batch-sharded operands it gathers them and runs the whole
+    batch on every device.  When a `with mesh:` context splits the
+    batch (dim 0 of every arg not listed in `replicated`), this maps `fn`
+    over the shards with `jax.shard_map` instead, so each device runs the
+    kernel on its own rows; `fn` must be row-independent and return
+    batch-leading arrays.  A batch that does not split (a single admitted
+    or retiring row) runs whole on every device: the TPU compiler refuses
+    a kernel outside `shard_map` in a mesh program either way.  Outside a
+    mesh it is `fn(*args)`.
+
+    `head_dim` names a dim that `fn` also treats independently (attention
+    heads, in the sharded args and the output alike).  It is split over
+    the tensor-parallel axis when the mesh has one of size > 1 and it
+    divides every such dim, the layout the head-aligned TP projections
+    produce; otherwise each tensor-parallel device runs every head, as
+    the replicated projections of a non-dividing head count do."""
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return fn(*args)
+    cfg = ShardCfg()
+    sharded = [a for i, a in enumerate(args) if i not in replicated]
+    entries = [batch_axes_entry(mesh, cfg, sharded[0].shape[0])]
+    if head_dim is not None:
+        tp = mesh.shape.get(cfg.tp_axis, 1)
+        split = tp > 1 and all(a.shape[head_dim] % tp == 0 for a in sharded)
+        entries += [None] * (head_dim - 1) \
+            + [cfg.tp_axis if split else None]
+    spec = P(*entries)
+    in_specs = tuple(P() if i in replicated else spec
+                     for i in range(len(args)))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*args)
 
 
 def constrain_batch(x: Array) -> Array:

@@ -18,6 +18,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from ...distributed.sharding import per_batch_shard
 from .ref import attention_ref
 from .kernel import flash_attention
 
@@ -120,12 +121,13 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             impl = "blocked"
         else:
             impl = "ref"
-    if impl == "pallas":
-        return flash_attention(q, k, v, q_offset=q_offset, causal=causal,
-                               window=window)
-    if impl == "pallas_interpret":
-        return flash_attention(q, k, v, q_offset=q_offset, causal=causal,
-                               window=window, interpret=True)
+    if impl in ("pallas", "pallas_interpret"):
+        launch = lambda q, k, v, off: flash_attention(
+            q, k, v, q_offset=off, causal=causal, window=window,
+            interpret=impl == "pallas_interpret")
+        return per_batch_shard(launch, q, k, v,
+                               jnp.asarray(q_offset, jnp.int32),
+                               replicated=(3,), head_dim=2)
     if impl == "blocked":
         return blocked_attention(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset)

@@ -1,8 +1,11 @@
 """Dispatch wrappers for the dct2 / fused-BDM kernels."""
 from __future__ import annotations
 
+import functools
+
 import jax
 
+from ...distributed.sharding import per_batch_shard
 from . import ref as _ref
 from . import kernel as _kernel
 
@@ -17,10 +20,10 @@ def _resolve(impl: str) -> str:
 
 def dct2(x: Array, inverse: bool = False, impl: str = "auto") -> Array:
     impl = _resolve(impl)
-    if impl == "pallas":
-        return _kernel.dct2(x, inverse=inverse)
-    if impl == "pallas_interpret":
-        return _kernel.dct2(x, inverse=inverse, interpret=True)
+    if impl in ("pallas", "pallas_interpret"):
+        return per_batch_shard(functools.partial(
+            _kernel.dct2, inverse=inverse,
+            interpret=impl == "pallas_interpret"), x)
     return _ref.idct2_ref(x) if inverse else _ref.dct2_ref(x)
 
 

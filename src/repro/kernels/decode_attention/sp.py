@@ -22,7 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
@@ -73,11 +72,11 @@ def sp_decode_attention(q: Array, k: Array, v: Array, cache_len: Array,
     other = [a for a in mesh.axis_names if a != seq_axis]
     rep_spec = P()
     kv_spec = P(None, seq_axis)            # (B, S/tp, Hkv, Dh) — add lead axis below
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep_spec, P(None, None, seq_axis), P(None, None, seq_axis),
                   P(None)),
-        out_specs=rep_spec, check_rep=False)
+        out_specs=rep_spec, check_vma=False)
     # shard_map wants the sharded dim explicit: add a dummy lead axis that
     # carries the (1, B, S, Hkv, Dh) layout with S sharded
     return fn(q, k[None], v[None], jnp.asarray(cache_len, jnp.int32).reshape(1))
@@ -106,11 +105,11 @@ def sp_cache_update(k_cache: Array, v_cache: Array, k_new: Array, v_new: Array,
         vc_out = jnp.where(in_range, vc_new, vc0)
         return kc_out[None], vc_out[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, seq_axis), P(None, None, seq_axis), P(), P(),
                   P(None)),
         out_specs=(P(None, None, seq_axis), P(None, None, seq_axis)),
-        check_rep=False)
+        check_vma=False)
     return tuple(t[0] for t in fn(k_cache[None], v_cache[None], k_new, v_new,
                                   jnp.asarray(cache_len, jnp.int32).reshape(1)))

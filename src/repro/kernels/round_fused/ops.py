@@ -31,12 +31,13 @@ reported by benchmarks/roofline.py's serving mode.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...distributed.sharding import per_batch_shard
 from .kernel import N_INTS, round_fused, round_predict as _predict_pallas
 from .ref import draw_step_noise, round_predict_ref, round_update_ref
 
@@ -86,9 +87,10 @@ def round_predict(u, hist, kc, cfg, bank, eps_c, *, kf: int,
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(impl)
     blks, dis = _stage_factors(bank, cfg, kc, kf, False, predict_only=True)
-    return _predict_pallas(blks, dis, bank.diag, u, hist, eps_c, kf=kf,
-                           block_d=block_d,
-                           interpret=(impl == "pallas_interpret"))
+    launch = functools.partial(_predict_pallas, kf=kf, block_d=block_d,
+                               interpret=(impl == "pallas_interpret"))
+    return per_batch_shard(launch, blks, dis, bank.diag, u, hist, eps_c,
+                           replicated=(2,))
 
 
 def round_update(u, hist, k, kc, cfg, fam, prec, keys, active, bank, eps_c,
@@ -116,23 +118,29 @@ def round_update(u, hist, k, kc, cfg, fam, prec, keys, active, bank, eps_c,
         noise_c = _draw_noise_c(sde, keys, kc, bank.alg[cfg], state_shape,
                                 u.dtype)
 
+    if gen_noise and not jax.config.jax_threefry_partitionable:
+        raise NotImplementedError(
+            "the in-kernel noise draw reproduces jax.random under the "
+            "default jax_threefry_partitionable=True counter layout only")
+
     mine = active & (fam == fam_index) & (prec == prec_index)
     use_c = (bank.corrector[cfg] & (kc < bank.n_steps[cfg] - 1)) \
         if with_corrector else jnp.zeros_like(active)
     ints = jnp.stack(
-        [kc, k, bank.n_steps[cfg], mine.astype(jnp.int32),
-         bank.stochastic[cfg].astype(jnp.int32), use_c.astype(jnp.int32),
-         active.astype(jnp.int32), bank.alg[cfg]],
+        [kc, mine.astype(jnp.int32), bank.stochastic[cfg].astype(jnp.int32),
+         use_c.astype(jnp.int32), bank.alg[cfg]],
         axis=1).astype(jnp.int32)
 
     blks, dis = _stage_factors(bank, cfg, kc, kf, with_corrector)
-    n = int(np.prod(state_shape))
-    u2, h2, k2, a2 = round_fused(
-        ints, keys, blks, dis, bank.diag, u, hist, eps_c,
-        eps_n_c=eps_n_c, noise_c=noise_c, kf=kf, n=n,
-        with_corrector=with_corrector, gen_noise=gen_noise,
-        block_d=block_d, interpret=(impl == "pallas_interpret"))
-    return u2, h2, k2, a2.astype(bool)
+    launch = functools.partial(
+        round_fused, kf=kf, with_corrector=with_corrector,
+        gen_noise=gen_noise, block_d=block_d,
+        interpret=(impl == "pallas_interpret"))
+    u2, h2 = per_batch_shard(launch, ints, keys, blks, dis, bank.diag, u,
+                             hist, eps_c, eps_n_c, noise_c, replicated=(4,))
+    # k-advance + retirement: the ref chain's ops on the (B,) slot ints
+    k2 = jnp.where(mine, k + 1, k)
+    return u2, h2, k2, jnp.where(mine, k2 < bank.n_steps[cfg], active)
 
 
 def fused_round_cost(*, B: int, K: int, Qb: int, kf: int, D: int,
@@ -153,7 +161,7 @@ def fused_round_cost(*, B: int, K: int, Qb: int, kf: int, D: int,
         streams_in += eps
     n_coef = 3 + Qb + (Qb if with_corrector else 0)
     smem = B * (N_INTS + 2 + n_coef * (kf * kf + 1))
-    bytes_moved = itemsize * (streams_in + state + hist) + 4 * 2 * B
+    bytes_moved = itemsize * (streams_in + state + hist)
     # per element of the kf-row output: each factor apply is 2 mul + 1 add
     # per (c, c2) term; predictor sums Qb + 1 applies, stochastic 2 more,
     # corrector Qb more; noise gen ~ const * eps elements (VPU transcendental)
@@ -184,11 +192,11 @@ def staticcheck_entries():
 
     def commit_trace(ii, kk, bl, di_, po, uu, hh, ee):
         return round_fused(ii, kk, bl, di_, po, uu, hh, ee,
-                           kf=kf, n=kf * D, with_corrector=False)
+                           kf=kf, with_corrector=False)
 
     def commit_corr_trace(ii, kk, bl, di_, po, uu, hh, ee, en):
         return round_fused(ii, kk, bl, di_, po, uu, hh, ee, eps_n_c=en,
-                           kf=kf, n=kf * D, with_corrector=True)
+                           kf=kf, with_corrector=True)
 
     blks_p = jnp.zeros((B, 1 + Qb, kf, kf), jnp.float32)
     blks = jnp.zeros((B, 3 + Qb, kf, kf), jnp.float32)

@@ -4,7 +4,9 @@ This is the *exact* post-score-eval chain of the historical
 `make_diffusion_serve_step` bank mode + `make_diffusion_round_step`
 masking, transplanted op-for-op (the PR-5 `_apply_factored_canonical`
 discipline, extended to the whole round): same gathers, same
-`apply_factored` calls in the same order, same left-associated term sums,
+factor applies in the same order (`apply_factored_ref`, pure jnp on every
+backend — this chain is the oracle the kernel is checked against on the
+chip), same left-associated term sums,
 same `jnp.where` masking with identical operand order.  Under jit the
 graph is therefore the same program as the stitched chain it replaces,
 and the result is BITWISE equal to it — which is what lets the serving
@@ -37,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core.coeffs import ALG_GMM, GMM_C, GMM_SALT
-from ..ei_update.ops import apply_factored, pad_channels
+from ..ei_update.ops import pad_channels
+from ..ei_update.ref import apply_factored_ref
 
 Array = jax.Array
 
@@ -96,10 +99,10 @@ def _predict(u, hist2, kc, cfg, bank, *, kf):
     """Eq. 19a on an already-shifted history: u_lin + sum_j pC_j hist_j.
     Returns (u_lin, u_pred); term order matches the stitched chain."""
     ub = u[:, :kf]
-    u_lin = apply_factored(*_gat(bank, "psi", cfg, kc, kf), ub)
+    u_lin = apply_factored_ref(*_gat(bank, "psi", cfg, kc, kf), ub)
     u_pred = u_lin
     for j in range(hist2.shape[1]):
-        u_pred = u_pred + apply_factored(
+        u_pred = u_pred + apply_factored_ref(
             *_gatq(bank, "pC", j, cfg, kc, kf), hist2[:, j, :kf])
     return u_lin, u_pred
 
@@ -138,8 +141,9 @@ def round_update_ref(u, hist, k, kc, cfg, fam, prec, keys, active, bank,
         noise = draw_step_noise(sde, keys, kc, bank.alg[cfg],
                                 state_shape, u.dtype)
         noise_c = sde.canonicalize(noise)
-    u_sto = u_lin + apply_factored(*_gat(bank, "B", cfg, kc, kf), eps_c) \
-        + apply_factored(*_gat(bank, "P_chol", cfg, kc, kf), noise_c)
+    u_sto = u_lin \
+        + apply_factored_ref(*_gat(bank, "B", cfg, kc, kf), eps_c) \
+        + apply_factored_ref(*_gat(bank, "P_chol", cfg, kc, kf), noise_c)
     bmask = lambda m: m.reshape((-1, 1, 1))
     u_next = jnp.where(bmask(bank.stochastic[cfg]), u_sto, u_pred)
 
@@ -147,10 +151,10 @@ def round_update_ref(u, hist, k, kc, cfg, fam, prec, keys, active, bank,
         if eps_n_c is None:
             raise ValueError("with_corrector=True needs eps_n_c (the "
                              "canonicalized corrector eval at u_pred)")
-        u_corr = u_lin + apply_factored(
+        u_corr = u_lin + apply_factored_ref(
             *_gatq(bank, "cC", 0, cfg, kc, kf), eps_n_c)
         for j in range(1, hist2.shape[1]):
-            u_corr = u_corr + apply_factored(
+            u_corr = u_corr + apply_factored_ref(
                 *_gatq(bank, "cC", j, cfg, kc, kf), hist2[:, j - 1, :kf])
         # Alg. 1: no corrector on the final step (k == N_c - 1)
         use_c = bank.corrector[cfg] & (kc < bank.n_steps[cfg] - 1)

@@ -8,18 +8,29 @@ in its very first lines).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis in Auto mode.  The sharding rules of
+    distributed/sharding.py place arrays with NamedShardings and let the
+    SPMD partitioner propagate from there; the installed JAX's default
+    Explicit axes would instead type each array's sharding and refuse the
+    batch gathers and slot scatters the engines trace."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over the actually-present devices (tests / smoke runs)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str) -> dict:

@@ -79,6 +79,7 @@ from ..core import SamplerConfig
 from ..models.registry import Arch
 from ..serve import (DiffusionEngine, ReplicaSpec, Router, RouterConfig,
                      ServeRequest, TokenEngine)
+from .compile_cache import enable_compile_cache
 from .mesh import make_serve_mesh
 
 
@@ -366,6 +367,7 @@ def main(argv=None) -> int:
                                  **kw})
         except ValueError as e:
             ap.error(str(e))
+    enable_compile_cache()
     return _serve_samples(args) if args.diffusion else _serve_tokens(args)
 
 

@@ -26,6 +26,8 @@ from ..distributed.sharding import ShardCfg, param_shardings
 from ..ckpt.store import CheckpointStore
 from ..data.pipeline import TokenPipeline, MixturePipeline
 from . import steps as steps_lib
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def make_auto_mesh() -> Mesh:
@@ -36,7 +38,7 @@ def make_auto_mesh() -> Mesh:
         if n % m == 0 and m <= n:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def train_lm(args) -> dict:
@@ -163,6 +165,7 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.diffusion:
         train_diffusion(args)
     elif args.arch:

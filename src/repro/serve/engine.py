@@ -78,10 +78,8 @@ Array = jax.Array
 
 
 def _cache_size(jitted) -> int:
-    try:
-        return int(jitted._cache_size())
-    except Exception:                                   # pragma: no cover
-        return -1
+    """Compiled programs in a `jax.jit` function's cache."""
+    return int(jitted._cache_size())
 
 
 def _make_row_scatter(batch_axes: List[int], out_shardings=None):

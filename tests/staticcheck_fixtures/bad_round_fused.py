@@ -27,7 +27,7 @@ def bad_round_fused_trace():
     """The megakernel launched without `_pad_last`: block_d=32 on D=48
     (PL201) and grid (B, 2) whose second d-tile spans [32, 64) (PL202)."""
     kernel = _make_round_kernel(
-        kf=_KF, K=_K, Qb=_QB, D=_D, n=_KF * _D, block_d=_BLK,
+        kf=_KF, K=_K, Qb=_QB, D=_D, block_d=_BLK,
         with_corrector=False, gen_noise=False)
 
     def launch(ints, keys, blks, dis, pool, u, hist, eps, noise):
@@ -35,13 +35,13 @@ def bad_round_fused_trace():
             kernel,
             grid=(_B, 2),
             in_specs=[
-                pl.BlockSpec((1, N_INTS), lambda b, d: (b, 0),
+                pl.BlockSpec((1, 1, N_INTS), lambda b, d: (b, 0, 0),
                              memory_space=_SMEM),
-                pl.BlockSpec((1, 2), lambda b, d: (b, 0),
+                pl.BlockSpec((1, 1, 2), lambda b, d: (b, 0, 0),
                              memory_space=_SMEM),
-                pl.BlockSpec((1, _C, _KF, _KF), lambda b, d: (b, 0, 0, 0),
+                pl.BlockSpec((1, 1, _C * _KF * _KF), lambda b, d: (b, 0, 0),
                              memory_space=_SMEM),
-                pl.BlockSpec((1, _C), lambda b, d: (b, 0),
+                pl.BlockSpec((1, 1, _C), lambda b, d: (b, 0, 0),
                              memory_space=_SMEM),
                 pl.BlockSpec((_PB, _BLK), lambda b, d: (0, d)),
                 pl.BlockSpec((1, _K, _BLK), lambda b, d: (b, 0, d)),
@@ -52,23 +52,19 @@ def bad_round_fused_trace():
             out_specs=[
                 pl.BlockSpec((1, _K, _BLK), lambda b, d: (b, 0, d)),
                 pl.BlockSpec((1, _QB, _K, _BLK), lambda b, d: (b, 0, 0, d)),
-                pl.BlockSpec((1,), lambda b, d: (b,), memory_space=_SMEM),
-                pl.BlockSpec((1,), lambda b, d: (b,), memory_space=_SMEM),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((_B, _K, _D), jnp.float32),
                 jax.ShapeDtypeStruct((_B, _QB, _K, _D), jnp.float32),
-                jax.ShapeDtypeStruct((_B,), jnp.int32),
-                jax.ShapeDtypeStruct((_B,), jnp.int32),
             ],
             interpret=True,
         )(ints, keys, blks, dis, pool, u, hist, eps, noise)
 
     return jax.make_jaxpr(launch)(
-        jnp.zeros((_B, N_INTS), jnp.int32),
-        jnp.zeros((_B, 2), jnp.uint32),
-        jnp.zeros((_B, _C, _KF, _KF), jnp.float32),
-        jnp.zeros((_B, _C), jnp.int32),
+        jnp.zeros((_B, 1, N_INTS), jnp.int32),
+        jnp.zeros((_B, 1, 2), jnp.int32),
+        jnp.zeros((_B, 1, _C * _KF * _KF), jnp.float32),
+        jnp.zeros((_B, 1, _C), jnp.int32),
         jnp.zeros((_PB, _D), jnp.float32),
         jnp.zeros((_B, _K, _D), jnp.float32),
         jnp.zeros((_B, _QB, _K, _D), jnp.float32),

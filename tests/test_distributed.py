@@ -15,8 +15,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.distributed.sharding import (ShardCfg, bank_shardings, param_spec,
                                         batch_spec, kv_cache_spec)
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 CFG = ShardCfg()
 
 
@@ -149,6 +149,7 @@ def test_fsdp_train_step_multi_device():
         from repro.launch.steps import make_train_step, shardings_for
         from repro.optim.adamw import AdamWCfg, adamw_init
         from repro.distributed.sharding import ShardCfg, param_shardings, batch_spec
+        from repro.launch.mesh import make_mesh
 
         spec = get_arch("gemma3-1b", reduced=True)
         arch = Arch(spec)
@@ -163,7 +164,7 @@ def test_fsdp_train_step_multi_device():
         opt0 = adamw_init(params, opt_cfg)
         p_ref, _, m_ref = jax.jit(step)(params, opt0, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = ShardCfg()
         psh = param_shardings(params, mesh, cfg)
         params_d = jax.device_put(params, psh)
@@ -193,17 +194,18 @@ def test_elastic_remesh_restart():
         from repro.models.registry import Arch
         from repro.distributed.sharding import ShardCfg, param_shardings
         from repro.ckpt.store import CheckpointStore
+        from repro.launch.mesh import make_mesh
 
         spec = get_arch("gemma3-1b", reduced=True)
         arch = Arch(spec)
         params = arch.init(jax.random.PRNGKey(0))
         d = tempfile.mkdtemp()
         store = CheckpointStore(d)
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh((4, 2), ("data", "model"))
         p1 = jax.device_put(params, param_shardings(params, mesh1, ShardCfg()))
         store.save(1, p1, blocking=True)
         # "restart" on a different mesh shape
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         like = jax.tree.map(
             lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
             params, param_shardings(params, mesh2, ShardCfg()))

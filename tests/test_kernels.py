@@ -313,3 +313,123 @@ def test_round_fused_kernel_matches_ref(family, q2, corr, sto):
             np.testing.assert_allclose(
                 a, b, rtol=1e-5, atol=1e-5,
                 err_msg=f"{family} {nm}: beyond the kf=2 FMA gap")
+
+
+@pytest.mark.parametrize("D,block_d,want", [
+    (48, 2048, (48, 48)),          # whole row: one tile, no padding
+    (3072, 2048, (1536, 3072)),    # CIFAR row: 2 x 1536, no padding
+    (192, 128, (128, 256)),        # no 128-multiple divides 192: pad
+])
+def test_round_fused_block_d(D, block_d, want):
+    """The d-tile is legal for the TPU compiler (the whole row, or a
+    multiple of 128 lanes) and pads only when no such width divides D."""
+    from repro.kernels.round_fused.kernel import pick_block_d
+    assert pick_block_d(D, block_d) == want
+
+
+_MESH_KERNELS = """
+import functools
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import CoeffCache, SamplerConfig
+from repro.sde import VPSDE
+from repro.kernels.attention import ops as attn_ops
+from repro.kernels.dct2 import ops as dct_ops
+from repro.kernels.round_fused import ops as rf
+from repro.launch.mesh import make_local_mesh
+
+shape, sde = (4, 4, 3), VPSDE()
+cache = CoeffCache({"vpsde": sde}, data_shape=shape)
+idx = [cache.index_of(SamplerConfig(nfe=4, q=2, corrector=True)),
+       cache.index_of(SamplerConfig(nfe=6, lam=0.7))]
+bank = cache.factored_bank
+K, D, Qb = cache.k_max, int(np.prod(shape)), bank.pC_blk.shape[2]
+pallas = dict(impl="pallas_interpret")
+
+def launches(fn, *args):
+    # leading output dim of every pallas_call in fn's jaxpr
+    dims = []
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                dims.append(e.outvars[0].aval.shape[:3])
+                continue
+            for v in e.params.values():
+                for j in (v if isinstance(v, (list, tuple)) else [v]):
+                    j = getattr(j, "jaxpr", j)
+                    if hasattr(j, "eqns"):
+                        walk(j)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return dims
+
+for B in (8, 1):
+    rng = np.random.default_rng(B)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    ci = jnp.asarray([idx[i % 2] for i in range(B)], jnp.int32)
+    k = jnp.asarray(rng.integers(0, 4, B), jnp.int32)
+    kc = jnp.clip(k, 0, bank.n_steps[ci] - 1)
+    u, hist, eps = normal(B, K, D), normal(B, Qb, K, D), normal(B, 1, D)
+    keys = jnp.asarray(rng.integers(0, 2**32, (B, 2), dtype=np.uint64),
+                       jnp.uint32)
+    cases = [
+        ("round_update", lambda *a: rf.round_update(
+            *a[:-1], eps_n_c=a[-1], sde=sde, kf=1, fam_index=0,
+            state_shape=sde.state_shape(shape), with_corrector=True,
+            **pallas),
+         (u, hist, k, kc, ci, jnp.zeros((B,), jnp.int32),
+          jnp.zeros((B,), jnp.int32), keys,
+          jnp.asarray(rng.random(B) < 0.8), bank, eps, normal(B, 1, D))),
+        ("round_predict", functools.partial(rf.round_predict, kf=1, **pallas),
+         (u, hist, kc, ci, bank, eps)),
+        ("dct2", functools.partial(dct_ops.dct2, **pallas),
+         (normal(B, 3, 8, 8),)),
+        ("attention", functools.partial(attn_ops.attention, causal=False,
+                                        **pallas),
+         (normal(B, 16, 4, 8), normal(B, 16, 4, 8), normal(B, 16, 4, 8))),
+    ]
+    for name, fn, args in cases:
+        want = jax.jit(fn)(*args)
+        for data, model in [(4, 1)] + [(2, 2)] * (name == "attention"):
+            with make_local_mesh(data=data, model=model):
+                got = jax.jit(fn)(*args)
+                dims = launches(fn, *args)
+            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+            print(name, B, data, model, dims)
+"""
+
+
+def test_round_fused_under_data_mesh_matches_single_device():
+    """On 4 host devices, every Pallas call of the diffusion round
+    (round_update with the corrector and in-kernel noise, round_predict,
+    dct2, flash attention; interpret mode) launches through
+    `per_batch_shard` once per batch shard and gives the plain launch's
+    bits: B/4 rows per launch on a data=4 mesh, the whole row on every
+    device when B=1 does not split, and attention's heads split over
+    `model` on a data=2, model=2 mesh."""
+    import ast
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _MESH_KERNELS],
+                       capture_output=True, text=True, env=env, cwd=root)
+    assert r.returncode == 0, r.stdout + r.stderr
+    # per launch: leading dims of the first output (attention's is
+    # (B, H, S), the others' lead with B)
+    want = {(n, B, m): d for n, B, m, d in [
+        ("round_update", 8, "4 1", (2,)), ("round_update", 1, "4 1", (1,)),
+        ("round_predict", 8, "4 1", (2,)), ("round_predict", 1, "4 1", (1,)),
+        ("dct2", 8, "4 1", (2,)), ("dct2", 1, "4 1", (1,)),
+        ("attention", 8, "4 1", (2, 4)), ("attention", 1, "4 1", (1, 4)),
+        ("attention", 8, "2 2", (4, 2)), ("attention", 1, "2 2", (1, 2))]}
+    got = {}
+    for line in r.stdout.splitlines():
+        name, B, data, model, dims = line.split(" ", 4)
+        got[(name, int(B), f"{data} {model}")] = ast.literal_eval(dims)
+    assert set(got) == set(want), r.stdout
+    for key, lead in want.items():
+        assert len(got[key]) == 1, (key, got[key])
+        assert got[key][0][:len(lead)] == lead, (key, got[key])

@@ -23,8 +23,9 @@ def test_sp_decode_matches_ref_and_update_is_local():
         import numpy as np, jax, jax.numpy as jnp
         from repro.kernels.decode_attention.sp import sp_decode_attention, sp_cache_update
         from repro.kernels.decode_attention.ref import decode_attention_ref
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = make_mesh((1, 4), ("data", "model"))
         B, Hq, Hkv, Dh, S = 2, 8, 2, 16, 64
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, Hq, Dh))

@@ -282,7 +282,7 @@ class TestSanitizer:
         import jax
         import jax.numpy as jnp
         from tools.staticcheck import jaxprcheck as jxc
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(lambda x: x * 2.0)(
                 jnp.zeros((2,), jnp.float64))
         rules = {f.rule for f in jxc.check_dtypes(closed.jaxpr, "fx")}

@@ -34,7 +34,10 @@ The reframe pattern (ROADMAP open item 1), applied to the router fleet:
      agree by construction, and tools/perf_guard.py EXACT-gates them).
 
 In CI this runs N local processes on one machine (the `serve-router`
-job).  On a real cluster the same three-phase shape maps onto k8s: one
+job).  The spawned replicas are pinned to the CPU: the harness checks the
+routing and fleet protocol and measures no device (a TPU chip belongs to
+one process, so on a chip the N replicas run in one process through the
+in-process router: `python -m repro.launch.serve --replicas N`).  On a real cluster the same three-phase shape maps onto k8s: one
 headless Service + StatefulSet of N replicas, each pod running
 `tools/launchgate.py --worker --replica $POD_ORDINAL --coordinator
 <pod-0-dns>:12355`, with the parent's wait/harvest/gate phases as a Job
@@ -260,6 +263,10 @@ def parent_main(args) -> int:
 
     # -- launch -----------------------------------------------------------
     env = dict(os.environ)
+    # the replicas check the fleet protocol, not a device: pinned to the
+    # CPU, because one chip serves one process (on a chip, N replicas run
+    # in one process: `repro.launch.serve --replicas N`)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     procs = []

@@ -41,6 +41,12 @@ def _dtype_bytes(aval) -> int:
     return int(np.dtype(aval.dtype).itemsize)
 
 
+def _block_dim(d):
+    """One block dim as an int: the installed Pallas wraps each dim of a
+    BlockSpec in a `Blocked(block_size=...)` record."""
+    return getattr(d, "block_size", d)
+
+
 def _eval_index_map(bm, idx) -> List[int]:
     import jax.core as jcore
     imj = bm.index_map_jaxpr
@@ -67,7 +73,7 @@ def check_pallas_eqn(eqn, label: str) -> List[Finding]:
     vmem_bytes = 0
     smem_bytes = 0
     for op, (bm, ashape) in enumerate(zip(bms, shapes)):
-        bshape = tuple(bm.block_shape)
+        bshape = tuple(_block_dim(d) for d in bm.block_shape)
         is_smem = "smem" in str(bm.block_aval).lower()
         nbytes = _dtype_bytes(bm.block_aval.inner_aval
                               if hasattr(bm.block_aval, "inner_aval")
