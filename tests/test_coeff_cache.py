@@ -14,7 +14,8 @@ from repro.core import (CoeffCache, SamplerConfig, bucket_size,
                         build_sampler_coeffs, time_grid)
 from repro.core.coeffs import (C_BUCKET_MIN, DIAG_BUCKET_MIN, N_BUCKET_MIN,
                                Q_BUCKET_MIN)
-from repro.sde import VPSDE, CLD, BDM
+from repro.sde import VPSDE, BDM
+from suite_support import default_cld
 
 
 def test_cache_hit_returns_identical_bank_object():
@@ -107,9 +108,9 @@ def test_bucket_size():
     assert bucket_size(33, 8) == 64
 
 
-def test_bank_works_for_block_family():
+def test_bank_works_for_block_family(cld):
     """CLD's (2,2) block coefficients stack with trailing coeff dims."""
-    cache = CoeffCache(CLD())
+    cache = CoeffCache(cld)
     cache.index_of(SamplerConfig(nfe=4, q=2))
     bank = cache.bank
     assert bank.psi.shape[2:] == (2, 2)
@@ -136,7 +137,7 @@ DATA_SHAPE = (4, 4, 3)
 
 
 def _multi_cache():
-    return CoeffCache({"vpsde": VPSDE(), "cld": CLD(),
+    return CoeffCache({"vpsde": VPSDE(), "cld": default_cld(),
                        "bdm": BDM(data_shape=DATA_SHAPE)},
                       data_shape=DATA_SHAPE)
 
@@ -211,10 +212,10 @@ def test_factored_bank_rows_embed_family_coeffs():
         assert not np.asarray(bank.pC_blk[c, :cfg.nfe, cfg.q:]).any()
 
 
-def test_single_family_cache_keeps_native_bank():
+def test_single_family_cache_keeps_native_bank(cld):
     """Back-compat: a single-family cache still exposes the family-native
     CoeffBank AND (given data_shape) the factored bank."""
-    cache = CoeffCache(CLD(), data_shape=DATA_SHAPE)
+    cache = CoeffCache(cld, data_shape=DATA_SHAPE)
     cache.index_of(SamplerConfig(nfe=4))
     assert cache.bank.psi.shape[2:] == (2, 2)
     D = int(np.prod(DATA_SHAPE))
@@ -260,6 +261,6 @@ def test_factored_bank_registration_is_incremental():
     assert b4.diag.shape[0] > b3.diag.shape[0]
 
 
-def test_kt_mapping_must_cover_families():
+def test_kt_mapping_must_cover_families(cld):
     with pytest.raises(ValueError, match="missing"):
-        CoeffCache({"vpsde": VPSDE(), "cld": CLD()}, kt={"vpsde": "R"})
+        CoeffCache({"vpsde": VPSDE(), "cld": cld}, kt={"vpsde": "R"})

@@ -8,6 +8,8 @@ import sys
 
 import pytest
 
+from suite_support import LIMIT_S
+
 
 def _run(args):
     env = dict(os.environ)
@@ -16,7 +18,7 @@ def _run(args):
     r = subprocess.run([sys.executable, "-m", "repro.launch.dryrun"] + args,
                        capture_output=True, text=True, env=env,
                        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                       timeout=900)
+                       timeout=LIMIT_S)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     return r.stdout
 

@@ -35,7 +35,8 @@ from repro.core.coeffs import DIAG_BUCKET_MIN, bucket_size
 from repro.kernels.ei_update.ops import (apply_factored, apply_packed,
                                          pad_channels)
 from repro.launch.steps import make_diffusion_serve_step
-from repro.sde import BDM, CLD, VPSDE
+from repro.sde import BDM, VPSDE
+from suite_support import default_cld
 
 try:
     from hypothesis import given, strategies as st
@@ -52,7 +53,7 @@ def make_sde(family, data_shape):
     if family == "vpsde":
         return VPSDE()
     if family == "cld":
-        return CLD()
+        return default_cld()
     return BDM(data_shape=tuple(data_shape))
 
 
@@ -160,7 +161,7 @@ class TestFactoredCoeffDifferential:
 def _bank_parts():
     """One multi-family cache (all families, q/corrector/stochastic configs)
     with both its factored bank and the dense oracle bank."""
-    cache = CoeffCache({"vpsde": VPSDE(), "cld": CLD(),
+    cache = CoeffCache({"vpsde": VPSDE(), "cld": default_cld(),
                         "bdm": BDM(data_shape=DATA_SHAPE)},
                        data_shape=DATA_SHAPE)
     cfgs = [SamplerConfig(nfe=4),

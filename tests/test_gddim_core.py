@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.sde import VPSDE, CLD, BDM, GaussianMixture, ExactScore
+from repro.sde import VPSDE, BDM, GaussianMixture, ExactScore
 from repro.core import (build_sampler_coeffs, time_grid, ddim_closed_form_check,
                         sample_gddim, sample_gddim_stochastic, sample_em,
                         sample_heun, sample_ancestral_bdm)
@@ -21,11 +21,6 @@ from repro.core import (build_sampler_coeffs, time_grid, ddim_closed_form_check,
 @pytest.fixture(scope="module")
 def vp():
     return VPSDE()
-
-
-@pytest.fixture(scope="module")
-def cld():
-    return CLD()
 
 
 # ---------------------------------------------------------------------------

@@ -2,17 +2,11 @@
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.sde import VPSDE, CLD, GaussianMixture, ExactScore
 from repro.sde.general import GeneralSDE
 from repro.core import build_sampler_coeffs, time_grid, sample_gddim
 from repro.core.likelihood import log_likelihood
-
-
-@pytest.fixture(scope="module")
-def gsde():
-    return GeneralSDE()
 
 
 class TestGeneralSDE:

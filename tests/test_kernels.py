@@ -1,10 +1,13 @@
 """Per-kernel validation: Pallas (interpret=True) vs pure-jnp ref oracles,
 swept over shapes and dtypes (assignment deliverable (c))."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+from suite_support import default_cld
 from repro.kernels.attention.ref import attention_ref
 from repro.kernels.attention.kernel import flash_attention
 from repro.kernels.attention.ops import blocked_attention
@@ -185,11 +188,11 @@ def test_bdm_ei_update_matches_ref(q, dtype):
                                np.asarray(ref, np.float32), **tol(dtype))
 
 
-def test_ei_update_is_the_gddim_step():
+def test_ei_update_is_the_gddim_step(cld):
     """The kernel reproduces one sample_gddim predictor step on CLD."""
-    from repro.sde import CLD, GaussianMixture, ExactScore
+    from repro.sde import GaussianMixture, ExactScore
     from repro.core import build_sampler_coeffs, time_grid
-    sde = CLD()
+    sde = cld
     ts = time_grid(sde, 6)
     co = build_sampler_coeffs(sde, ts, q=2)
     mix = GaussianMixture(np.array([[0.4, -0.2]]), np.array([0.05]), np.array([1.0]))
@@ -216,25 +219,23 @@ def test_ei_update_is_the_gddim_step():
 # ---------------------------------------------------------------------------
 # round_fused (the whole post-score-eval round commit, one launch)
 # ---------------------------------------------------------------------------
+@functools.cache
 def _round_fused_parts():
-    import functools
     from repro.core import CoeffCache, SamplerConfig
-    from repro.sde import VPSDE, CLD, BDM
-    if not hasattr(_round_fused_parts, "_cache"):
-        shape = (4, 4, 3)
-        cache = CoeffCache({"vpsde": VPSDE(), "cld": CLD(),
-                            "bdm": BDM(data_shape=shape)},
-                           data_shape=shape)
-        cfgs = [SamplerConfig(nfe=4), SamplerConfig(nfe=5, q=2),
-                SamplerConfig(nfe=6, lam=0.7),
-                SamplerConfig(nfe=4, family="cld"),
-                SamplerConfig(nfe=4, family="cld", q=2, corrector=True),
-                SamplerConfig(nfe=5, family="cld", lam=0.5),
-                SamplerConfig(nfe=4, family="bdm", q=2, corrector=True),
-                SamplerConfig(nfe=3, family="bdm", lam=0.5)]
-        idx = [cache.index_of(c) for c in cfgs]
-        _round_fused_parts._cache = (cache, cfgs, idx, shape)
-    return _round_fused_parts._cache
+    from repro.sde import VPSDE, BDM
+    shape = (4, 4, 3)
+    cache = CoeffCache({"vpsde": VPSDE(), "cld": default_cld(),
+                        "bdm": BDM(data_shape=shape)},
+                       data_shape=shape)
+    cfgs = [SamplerConfig(nfe=4), SamplerConfig(nfe=5, q=2),
+            SamplerConfig(nfe=6, lam=0.7),
+            SamplerConfig(nfe=4, family="cld"),
+            SamplerConfig(nfe=4, family="cld", q=2, corrector=True),
+            SamplerConfig(nfe=5, family="cld", lam=0.5),
+            SamplerConfig(nfe=4, family="bdm", q=2, corrector=True),
+            SamplerConfig(nfe=3, family="bdm", lam=0.5)]
+    idx = [cache.index_of(c) for c in cfgs]
+    return cache, cfgs, idx, shape
 
 
 # corners: family x q x corrector x stochastic — each case's slot list
@@ -262,7 +263,6 @@ def test_round_fused_kernel_matches_ref(family, q2, corr, sto):
     contraction (the ref einsum lowers to an FMA dot_general; see
     apply_factored_ref's docstring — same gap class as the
     `test_apply_factored_kernel_matches_ref` tolerance)."""
-    import functools
     from repro.kernels.round_fused import ops as rf
     cache, cfgs, idx, shape = _round_fused_parts()
     bank = cache.factored_bank

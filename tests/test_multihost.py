@@ -23,6 +23,7 @@ import jax
 import pytest
 
 from repro.distributed import multihost
+from suite_support import LIMIT_S
 
 
 # ---------------------------------------------------------------- unit
@@ -154,10 +155,15 @@ def test_two_process_fleet_kv_barrier_and_bitwise_union(tmp_path):
         cwd=repo, env=env, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True) for p in range(2)]
     outs = []
-    for p, proc in enumerate(procs):
-        out, _ = proc.communicate(timeout=600)
-        outs.append(out)
-        assert proc.returncode == 0, f"process {p}:\n{out}"
+    try:
+        for p, proc in enumerate(procs):
+            out, _ = proc.communicate(timeout=LIMIT_S)
+            outs.append(out)
+            assert proc.returncode == 0, f"process {p}:\n{out}"
+    finally:
+        for proc in procs:
+            proc.kill()
+            proc.wait()
     assert "KV-OK" in outs[0]
     assert "UNION-BITWISE-OK" in outs[0]
     for p in range(2):

@@ -1,4 +1,6 @@
 """Property-based tests (hypothesis) over the system's invariants."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -10,7 +12,8 @@ pytest.importorskip(
            "property tests degrade to a skip without it")
 from hypothesis import given, settings, strategies as st, HealthCheck
 
-from repro.sde import VPSDE, CLD, BDM
+from repro.sde import VPSDE, BDM
+from suite_support import default_cld
 from repro.models import rwkv6, common
 from repro.kernels.ei_update.ref import ei_update_ref
 from repro.kernels.ei_update.kernel import ei_update
@@ -26,18 +29,16 @@ ts_strategy = st.floats(min_value=1e-3, max_value=0.999)
 class TestSDEInvariants:
     @given(t=ts_strategy)
     @settings(**SLOW)
-    def test_cld_R_factorizes_sigma(self, t):
-        sde = CLD()
-        R = sde.R_np(t)
-        S = sde.Sigma_np(t)
+    def test_cld_R_factorizes_sigma(self, cld, t):
+        R = cld.R_np(t)
+        S = cld.Sigma_np(t)
         np.testing.assert_allclose(R @ R.T, S, rtol=1e-4, atol=1e-8)
 
     @given(t=ts_strategy, s=ts_strategy, r=ts_strategy)
     @settings(**SLOW)
-    def test_cld_psi_group_property(self, t, s, r):
-        sde = CLD()
-        lhs = sde.Psi_np(t, s) @ sde.Psi_np(s, r)
-        rhs = sde.Psi_np(t, r)
+    def test_cld_psi_group_property(self, cld, t, s, r):
+        lhs = cld.Psi_np(t, s) @ cld.Psi_np(s, r)
+        rhs = cld.Psi_np(t, r)
         np.testing.assert_allclose(lhs, rhs, rtol=1e-6, atol=1e-10)
 
     @given(t=ts_strategy, s=ts_strategy)
@@ -179,7 +180,7 @@ class TestPackingProperties:
         family=st.sampled_from(["scalar", "block", "freqdiag"]),
     )
     @settings(**SLOW)
-    def test_factored_coeff_matches_family_native_apply(self, B, seed,
+    def test_factored_coeff_matches_family_native_apply(self, cld, B, seed,
                                                         family):
         """factor_coeff's (k_max, k_max)-block x pooled-(D,)-diagonal pair
         applied via apply_factored equals the family's native structured
@@ -195,7 +196,7 @@ class TestPackingProperties:
         if family == "scalar":
             sde, coeff = VPSDE(), np.float64(rng.standard_normal())
         elif family == "block":
-            sde, coeff = CLD(), rng.standard_normal((2, 2))
+            sde, coeff = cld, rng.standard_normal((2, 2))
         else:
             sde = BDM(data_shape=data_shape)
             coeff = rng.standard_normal((4, 4, 1))
@@ -415,27 +416,21 @@ class TestRoundFusedProperties:
     dot_general accumulates with FMA; see `apply_factored_ref`)."""
 
     @staticmethod
+    @functools.cache
     def _parts():
-        import functools
         from repro.core import CoeffCache, SamplerConfig
-
-        @functools.lru_cache(maxsize=1)
-        def build():
-            shape = (4, 4, 3)
-            cache = CoeffCache({"vpsde": VPSDE(), "cld": CLD(),
-                                "bdm": BDM(data_shape=shape)},
-                               data_shape=shape)
-            cfgs = [SamplerConfig(nfe=4), SamplerConfig(nfe=5, q=2),
-                    SamplerConfig(nfe=6, lam=0.7),
-                    SamplerConfig(nfe=4, family="cld"),
-                    SamplerConfig(nfe=4, family="cld", q=2, corrector=True),
-                    SamplerConfig(nfe=4, family="bdm", q=2),
-                    SamplerConfig(nfe=3, family="bdm", lam=0.5)]
-            idx = [cache.index_of(c) for c in cfgs]
-            return cache, cfgs, idx, shape
-        if not hasattr(TestRoundFusedProperties, "_cached"):
-            TestRoundFusedProperties._cached = build()
-        return TestRoundFusedProperties._cached
+        shape = (4, 4, 3)
+        cache = CoeffCache({"vpsde": VPSDE(), "cld": default_cld(),
+                            "bdm": BDM(data_shape=shape)},
+                           data_shape=shape)
+        cfgs = [SamplerConfig(nfe=4), SamplerConfig(nfe=5, q=2),
+                SamplerConfig(nfe=6, lam=0.7),
+                SamplerConfig(nfe=4, family="cld"),
+                SamplerConfig(nfe=4, family="cld", q=2, corrector=True),
+                SamplerConfig(nfe=4, family="bdm", q=2),
+                SamplerConfig(nfe=3, family="bdm", lam=0.5)]
+        idx = [cache.index_of(c) for c in cfgs]
+        return cache, cfgs, idx, shape
 
     @given(
         B=st.integers(min_value=1, max_value=3),
@@ -446,7 +441,6 @@ class TestRoundFusedProperties:
     @settings(**SLOW)
     def test_round_fused_kernel_matches_ref(self, B, family, with_corrector,
                                             seed):
-        import functools
         from repro.kernels.round_fused import ops as rf
         cache, cfgs, idx, shape = self._parts()
         bank = cache.factored_bank

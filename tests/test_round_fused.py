@@ -31,9 +31,10 @@ import pytest
 from repro.core import CoeffCache, SamplerConfig
 from repro.launch.steps import (make_diffusion_round_step,
                                 make_diffusion_round_step_stitched)
-from repro.sde import BDM, CLD, VPSDE
+from repro.sde import BDM, VPSDE
 from repro.serve.state import DiffusionState
 from repro.kernels.round_fused import ops as rf_ops
+from suite_support import default_cld
 
 DATA_SHAPE = (4, 4, 3)
 FAMILIES = ["vpsde", "cld", "bdm"]
@@ -41,7 +42,7 @@ FAMILIES = ["vpsde", "cld", "bdm"]
 
 @functools.lru_cache(maxsize=1)
 def _bank_parts():
-    cache = CoeffCache({"vpsde": VPSDE(), "cld": CLD(),
+    cache = CoeffCache({"vpsde": VPSDE(), "cld": default_cld(),
                         "bdm": BDM(data_shape=DATA_SHAPE)},
                        data_shape=DATA_SHAPE)
     cfgs = [SamplerConfig(nfe=4),

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.sde import VPSDE, CLD, BDM, GaussianMixture, ExactScore, dct_nd, idct_nd
+from repro.sde import VPSDE, BDM, GaussianMixture, ExactScore, dct_nd, idct_nd
 
 
 # ---------------------------------------------------------------------------
@@ -40,41 +40,35 @@ class TestVPSDE:
 # CLD: Lyapunov / Eq. 17 invariants + Monte-Carlo marginal agreement
 # ---------------------------------------------------------------------------
 class TestCLD:
-    def test_RRt_equals_sigma_on_range(self):
-        cld = CLD()
+    def test_RRt_equals_sigma_on_range(self, cld):
         for t in [1e-3, 0.01, 0.05, 0.2, 0.5, 0.8, 1.0]:
             S, R = cld.Sigma_np(t), cld.R_np(t)
             assert np.abs(R @ R.T - S).max() < 5e-4, t
 
-    def test_L_is_cholesky(self):
-        cld = CLD()
+    def test_L_is_cholesky(self, cld):
         L = cld.L_np(0.4)
         assert L[0, 1] == pytest.approx(0.0)
         assert np.abs(L @ L.T - cld.Sigma_np(0.4)).max() < 1e-12
 
-    def test_R_differs_from_L(self):
+    def test_R_differs_from_L(self, cld):
         # the paper's whole point: the gDDIM branch is NOT the Cholesky factor
-        cld = CLD()
         assert np.abs(cld.R_np(0.5) - cld.L_np(0.5)).max() > 0.5
 
-    def test_sigma_solves_lyapunov(self):
-        cld = CLD()
+    def test_sigma_solves_lyapunov(self, cld):
         t, h = 0.3, 1e-6
         dS = (cld.Sigma_np(t + h) - cld.Sigma_np(t - h)) / (2 * h)
         S = cld.Sigma_np(t)
         rhs = cld.A @ S + S @ cld.A.T + cld.G2_np(t)
         assert np.abs(dS - rhs).max() < 1e-5
 
-    def test_psi_transition_ode(self):
-        cld = CLD()
+    def test_psi_transition_ode(self, cld):
         t, h = 0.6, 1e-6
         dP = (cld.Psi_np(t + h, 0.0) - cld.Psi_np(t - h, 0.0)) / (2 * h)
         assert np.abs(dP - cld.A @ cld.Psi_np(t, 0.0)).max() < 1e-4
 
-    def test_forward_marginal_monte_carlo(self):
+    def test_forward_marginal_monte_carlo(self, cld):
         """Simulate the forward CLD with EM; sample mean/cov must match
         Psi(t,0) u0 / Sigma_t.  This validates F, G, Psi, Sigma jointly."""
-        cld = CLD()
         rng = np.random.default_rng(0)
         n, t_end, n_steps = 20000, 0.5, 400
         x0 = np.array([1.3])
@@ -200,8 +194,7 @@ class TestExactScore:
         u = np.array([[0.3, 0.1]])
         self._fd_check(vp, mix, u, 0.4)
 
-    def test_score_vs_fd_cld(self):
-        cld = CLD()
+    def test_score_vs_fd_cld(self, cld):
         mix = GaussianMixture(np.array([[1.0, -1.0]]), np.array([0.3]), np.array([1.0]))
         u = np.array([[[0.3, 0.1], [-0.2, 0.4]]])  # (1, 2, 2)
         self._fd_check(cld, mix, u, 0.4)

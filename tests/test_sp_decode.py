@@ -5,6 +5,8 @@ import subprocess
 import sys
 import textwrap
 
+from suite_support import LIMIT_S
+
 
 def run_with_devices(n, code):
     env = dict(os.environ)
@@ -13,7 +15,7 @@ def run_with_devices(n, code):
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env,
                        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                       timeout=600)
+                       timeout=LIMIT_S)
     assert r.returncode == 0, r.stdout + r.stderr
     return r.stdout
 

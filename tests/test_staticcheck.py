@@ -17,6 +17,7 @@ import sys
 
 import pytest
 
+from suite_support import LIMIT_S
 from tools.staticcheck.astlint import lint_paths, lint_source
 from tools.staticcheck.findings import (Finding, apply_allowlist,
                                         parse_allowlist)
@@ -330,6 +331,6 @@ def test_quick_sanitizer_end_to_end():
     env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
     proc = subprocess.run(
         [sys.executable, "-m", "tools.staticcheck", "--sanitize", "--quick"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=1200)
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=LIMIT_S)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "ok (0 finding(s))" in proc.stdout
