@@ -9,7 +9,8 @@ import pytest
 
 from suite_support import default_cld
 from repro.kernels.attention.ref import attention_ref
-from repro.kernels.attention.kernel import flash_attention
+from repro.kernels.attention.kernel import _head_group, attention_plan, \
+    flash_attention, flash_attention_tiled
 from repro.kernels.attention.ops import blocked_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.decode_attention.kernel import decode_attention
@@ -34,6 +35,13 @@ ATTN_CASES = [
     (1, 256, 256, 4, 1, 32, True, 64, 0, 64, 64),        # MQA + window
     (1, 128, 128, 2, 2, 32, False, None, 0, 64, 64),     # bidirectional
     (2, 64, 256, 4, 2, 32, True, None, 192, 64, 64),     # offset (chunked)
+    (4, 256, 256, 12, 12, 64, False, None, 0, 128, 128), # DiT head layout
+    (2, 128, 128, 8, 2, 64, False, None, 0, 128, 64),    # GQA, bidirectional
+    (7, 256, 256, 12, 12, 64, False, None, 0, 128, 128), # prime batch: bt=1
+    (1, 2048, 2048, 1, 1, 32, False, None, 0, 256, 256), # too long: tiled
+    (2, 128, 128, 8, 8, 32, False, None, 0, 64, 64),     # 4 heads a window
+    (2, 1, 1500, 8, 8, 64, False, None, 0, 128, 128),    # whisper x-attn, decode
+    (2, 7, 1500, 8, 8, 64, False, None, 0, 128, 128),    # whisper x-attn, prompt
 ]
 
 
@@ -51,6 +59,75 @@ def test_flash_attention_matches_ref(case, dtype):
                           interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("Sq", [1, 7])
+def test_flash_attention_bf16_q_f32_kv_matches_ref(Sq):
+    """whisper-base's cross-attention as served: bf16 queries against the
+    float32 encoder memory's keys and values."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (2, Sq, 8, 64), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, 1500, 8, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 1500, 8, 64), jnp.float32)
+    ref = attention_ref(q, k, v, causal=False)
+    out = flash_attention(q, k, v, causal=False, interpret=True)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               **tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("case", [c for c in ATTN_CASES if c[6]])
+def test_flash_attention_causal_dispatch_is_the_tiled_kernel(case):
+    """Causal, windowed and offset calls run the tiled kernel unchanged."""
+    B, Sq, Sk, Hq, Hkv, Dh, causal, window, q_off, bq, bk = case
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(ks[0], (B, Sq, Hq, Dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, Sk, Hkv, Dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, Sk, Hkv, Dh), jnp.bfloat16)
+    kw = dict(causal=causal, window=window, q_offset=q_off, block_q=bq,
+              block_k=bk, interpret=True)
+    tiled = jax.jit(functools.partial(flash_attention_tiled, **kw))
+    np.testing.assert_array_equal(np.asarray(flash_attention(q, k, v, **kw)),
+                                  np.asarray(tiled(q, k, v)))
+
+
+DIT = (256, 256, 12, 12, 64)          # Sq, Sk, Hq, Hkv, Dh of a DiT-B/2 eval
+XATTN = (1, 1500, 8, 8, 64)           # whisper-base's cross-attention, decode
+PLAN_CASES = [
+    # (B, (Sq, Sk, Hq, Hkv, Dh), itemsize, causal, window, budget, plan)
+    (64, DIT, 2, False, None, None, ("whole", 4)),       # the backlog cells
+    (32, DIT, 2, False, None, None, ("whole", 4)),       # chip_smoke.py
+    (64, DIT, 4, False, None, None, ("whole", 2)),       # float32
+    (7, DIT, 2, False, None, None, ("whole", 1)),        # prime batch
+    (8, (256, 256, 12, 4, 64), 2, False, None, None, ("whole", 4)),  # GQA
+    (1, (1024, 1024, 8, 8, 128), 2, True, None, None, ("tiled", 0)),
+    (1, (1024, 1024, 8, 8, 128), 2, False, 256, None, ("tiled", 0)),
+    (1, (4096, 4096, 8, 8, 128), 2, False, None, None, ("tiled", 0)),
+    (64, DIT, 2, False, None, 2**21, ("tiled", 0)),      # bt=1 does not fit
+    (8, XATTN, 2, False, None, None, ("whole", 2)),
+    (8, XATTN, 4, False, None, None, ("whole", 1)),      # float32 k and v
+    (8, (1500, 1500, 8, 8, 64), 2, False, None, None, ("tiled", 0)),  # encoder
+]
+
+
+@pytest.mark.parametrize("case", PLAN_CASES)
+def test_attention_plan(case):
+    B, (Sq, Sk, Hq, Hkv, Dh), itemsize, causal, window, budget, want = case
+    kw = {} if budget is None else dict(vmem_budget=budget)
+    assert attention_plan(B, Sq, Sk, Hq, Hkv, Dh, itemsize, causal=causal,
+                          window=window, **kw) == want
+
+
+@pytest.mark.parametrize("shape, group", [
+    ((12, 12, 64), 2),          # DiT-B/2: two heads of 64 per 128 lanes
+    ((8, 8, 32), 4),
+    ((2, 2, 32), 1),            # fewer heads than a window holds
+    ((12, 4, 64), 1),           # GQA: one head per window
+    ((8, 8, 128), 1),
+])
+def test_head_group(shape, group):
+    assert _head_group(*shape) == group
 
 
 @pytest.mark.parametrize("case", ATTN_CASES[:3])
@@ -418,13 +495,15 @@ def test_round_fused_under_data_mesh_matches_single_device():
                        capture_output=True, text=True, env=env, cwd=root)
     assert r.returncode == 0, r.stdout + r.stderr
     # per launch: leading dims of the first output (attention's is
-    # (B, H, S), the others' lead with B)
+    # (B, S, H*Dh), the others' lead with B)
     want = {(n, B, m): d for n, B, m, d in [
         ("round_update", 8, "4 1", (2,)), ("round_update", 1, "4 1", (1,)),
         ("round_predict", 8, "4 1", (2,)), ("round_predict", 1, "4 1", (1,)),
         ("dct2", 8, "4 1", (2,)), ("dct2", 1, "4 1", (1,)),
-        ("attention", 8, "4 1", (2, 4)), ("attention", 1, "4 1", (1, 4)),
-        ("attention", 8, "2 2", (4, 2)), ("attention", 1, "2 2", (1, 2))]}
+        ("attention", 8, "4 1", (2, 16, 32)),
+        ("attention", 1, "4 1", (1, 16, 32)),
+        ("attention", 8, "2 2", (4, 16, 16)),
+        ("attention", 1, "2 2", (1, 16, 16))]}
     got = {}
     for line in r.stdout.splitlines():
         name, B, data, model, dims = line.split(" ", 4)

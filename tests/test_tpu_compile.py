@@ -1,6 +1,6 @@
 """Deviceless TPU v5e compiles of the serve round's Pallas kernels at full
 width (the 32x32x3 CIFAR row, D=3072; the DiT's 256-token, 12-head
-attention).
+attention, and a causal prefill's).
 
 Interpret mode checks what a kernel computes, not whether the chip's
 compiler takes it: it accepted SMEM blocks that the v5e compiler refuses.
@@ -12,6 +12,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -101,6 +103,48 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     from repro.kernels.attention.kernel import flash_attention
     qkv = ((B, 256, 12, 64), jnp.bfloat16)
     _compile(lambda q, k, v: flash_attention(q, k, v, causal=False),
+             [qkv] * 3, one_chip)
+
+
+def test_flash_attention_whole_sequence_compiles_for_v5e(one_chip):
+    """The backlog cells' call (64 slots, DiT-B/2 heads) on q, k, v in the
+    (B, S, H*Dh) layout the projections produce: one Mosaic call, no
+    transpose or copy around it."""
+    from repro.kernels.attention.kernel import flash_attention
+    b, s, h, dh = 64, 256, 12, 64
+
+    def launch(q, k, v):
+        q, k, v = (x.reshape(b, s, h, dh) for x in (q, k, v))
+        return flash_attention(q, k, v, causal=False).reshape(b, s, h * dh)
+
+    hlo = _compile(launch, [((b, s, h * dh), jnp.bfloat16)] * 3,
+                   one_chip).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    ops = {m.group(1) for m in re.finditer(r" = .*?\s([a-z][\w-]*)\(", hlo)}
+    assert not ops & {"transpose", "copy", "copy-start", "copy-done"}, ops
+
+
+@pytest.mark.parametrize("sq,kv_dtype", [(1, jnp.bfloat16), (1, jnp.float32),
+                                         (7, jnp.float32)],
+                         ids=["decode", "decode-f32kv", "prompt-f32kv"])
+def test_flash_attention_cross_attention_compiles_for_v5e(one_chip, sq,
+                                                          kv_dtype):
+    """whisper-base's decoder cross-attention (8 heads of 64 over the
+    1500-frame encoder memory, which the serve engine keeps in float32)
+    takes the whole-sequence path: one Mosaic call."""
+    from repro.kernels.attention.kernel import flash_attention
+    hlo = _compile(lambda q, k, v: flash_attention(q, k, v, causal=False),
+                   [((8, sq, 8, 64), jnp.bfloat16),
+                    ((8, 1500, 8, 64), kv_dtype), ((8, 1500, 8, 64), kv_dtype)],
+                   one_chip).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_flash_attention_causal_compiles_for_v5e(one_chip):
+    """A causal prefill call keeps the tiled kernel on the chip."""
+    from repro.kernels.attention.kernel import flash_attention
+    qkv = ((1, 1024, 8, 128), jnp.bfloat16)
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
              [qkv] * 3, one_chip)
 
 
