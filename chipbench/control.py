@@ -3,12 +3,12 @@
     python3 chipbench/control.py --workload <cell> --seeds 1,2,3 \
         --seconds 10 [--kinds control,sound]
 
-runs the cell once per seed and kind in one process and prints the number
-the check compares beside its limit.  `control` puts the reference with
-every matmul input in fp8 (per-tensor scale; the precision below the
-configuration's bf16) in the program's place for the compared samples and
-must read above the limit on every seed; `sound` is the program as
-configured.  The benchmark's own runs never run this.
+runs the cell once per seed and kind in one process and prints each
+family's compared number beside its limit, as [value, limit].  `control`
+puts the reference with every matmul input in fp8 (per-tensor scale; the
+precision below the configuration's bf16) in the program's place for the
+compared samples and must read above the limit on every seed; `sound` is
+the program as configured.  The benchmark's own runs never run this.
 """
 import argparse
 import json
@@ -40,10 +40,11 @@ def main(argv=None) -> int:
             except Abort as e:
                 print(f"chipbench: {e}", file=sys.stderr)
                 return 2
-            c = res["checks"]["sample_rel_l2_max"]
-            print(json.dumps({"kind": kind, "seed": seed,
-                              "sample_rel_l2_max": c["value"],
-                              "limit": c["limit"],
+            # one sample_rel_l2_max check per family of the config
+            gaps = {k: [c["value"], c["limit"]]
+                    for k, c in res["checks"].items()
+                    if k.startswith("sample_rel_l2_max")}
+            print(json.dumps({"kind": kind, "seed": seed, **gaps,
                               "correct": res["correct"],
                               "attempted": res["attempted"]}), flush=True)
     return 0

@@ -22,6 +22,12 @@ def dit_shapes(dit: Mapping) -> dict:
             "t_dim": dit["frequency_embedding_size"]}
 
 
+def state_rows(dit: Mapping) -> int:
+    """Rows of a family's packed state: the net's input channels over the
+    data's (2 for CLD's (x, v), 1 for pixels)."""
+    return dit["in_channels"] // dit["data_shape"][-1]
+
+
 def dit_eval_flops(dit: Mapping) -> dict:
     """FLOPs of one score-net evaluation of ONE sample, by part."""
     s = dit_shapes(dit)

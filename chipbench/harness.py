@@ -1,7 +1,15 @@
 """One run of one cell: set-up, the measured window, the correctness check
 and the metrics.  Everything cell-specific comes from files found by name
 through BENCHMARK.json: `configs/<config>.json` (`file` of the config
-entry), `traffic/<traffic>.json`, and `metrics/<metric>.py`.
+entry), `traffic/<traffic>.json`, `metrics/<metric>.py`, and the
+reference's `families/<kind>.py` of each SDE family the config holds.
+
+A config file holds one family (its `program`, `in_channels`, `sde` and
+`limits` among the DiT's widths) or several co-resident ones: a
+`families` list whose entries each give the keys of their own, over the
+keys the file shares.  A mix entry names its family under `family`, or
+takes the file's first.  Each family gets its own net, its own reference
+and its own limit; the engine holds them all.
 """
 from __future__ import annotations
 
@@ -36,14 +44,50 @@ def log(msg: str) -> None:
 # ---------------------------------------------------------------------------
 # finding a cell's files
 # ---------------------------------------------------------------------------
+def family_views(config: dict) -> Dict[str, dict]:
+    """{sde kind: the config as that family sees it}, in the file's order.
+    A file without `families` is its own single family."""
+    entries = config.get("families")
+    if entries is None:
+        return {config["sde"]["kind"]: config}
+    shared = {k: v for k, v in config.items() if k != "families"}
+    views: Dict[str, dict] = {}
+    for e in entries:
+        view = dict(shared, **e)
+        kind = view["sde"]["kind"]
+        if kind in views:
+            raise Abort(f"family {kind!r} is listed twice")
+        views[kind] = view
+    return views
+
+
 @dataclasses.dataclass
 class Cell:
+    """A cell as the run sees it: `families` is `family_views(config)`,
+    and every mix entry of `traffic` names its family."""
     name: str
     chips: int
     config: dict
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    families: Dict[str, dict] = dataclasses.field(init=False)
+
+    def __post_init__(self):
+        from . import families as families_lib
+        self.families = family_views(self.config)
+        first = next(iter(self.families))
+        mix = [dict({"family": first}, **m) for m in self.traffic["mix"]]
+        unknown = {m["family"] for m in mix} - set(self.families)
+        if unknown:
+            raise Abort(f"mix entries of family {sorted(unknown)}; the "
+                        f"config holds {list(self.families)}")
+        for view in self.families.values():
+            try:
+                families_lib.path_of(view["sde"]["kind"])
+            except FileNotFoundError as e:
+                raise Abort(str(e)) from None
+        self.traffic = dict(self.traffic, mix=mix)
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -178,16 +222,12 @@ class RunData:
     notes: Dict[str, str] = dataclasses.field(default_factory=dict)
 
 
-def _request_maker(cfg: dict):
+def make_request(i: int, f: dict):
     from repro.serve import ServeRequest
-
-    def make(i: int, f: dict):
-        return ServeRequest(rid=i, workload="diffusion", seed=f["seed"],
-                            nfe=f["nfe"], q=f["q"],
-                            corrector=f["corrector"], lam=f["lam"],
-                            algorithm=f["algorithm"],
-                            family=cfg["sde"]["kind"])
-    return make
+    return ServeRequest(rid=i, workload="diffusion", seed=f["seed"],
+                        nfe=f["nfe"], q=f["q"], corrector=f["corrector"],
+                        lam=f["lam"], algorithm=f["algorithm"],
+                        family=f["family"])
 
 
 def end_to_end(cell: Cell, records: List[traffic_lib.Record],
@@ -264,20 +304,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.core import SamplerConfig
     from repro.serve import DiffusionEngine
 
-    cfg, tr = cell.config, cell.traffic
+    tr = cell.traffic
     compiles = CompileCounter()
     t = time.perf_counter()
-    spec = build_spec(cfg)
+    specs = {fam: build_spec(view) for fam, view in cell.families.items()}
     from .weights import make_weights
-    params = make_weights(cfg, seed)
-    fam = cfg["sde"]["kind"]
+    params = {fam: make_weights(view, seed, family_index=j)
+              for j, (fam, view) in enumerate(cell.families.items())}
     menu = traffic_lib.menu(tr)
     # the default config is a served one, so the engine's Stage-I
     # coefficients at start are ones that the traffic uses
     engine = DiffusionEngine(
-        spec, params, batch_size=tr["slots"],
-        default_config=SamplerConfig(family=fam, **menu[0]))
-    make_request = _request_maker(cfg)
+        specs, params, batch_size=tr["slots"],
+        default_config=SamplerConfig(**menu[0]))
     log(f"setup: weights and engine {time.perf_counter() - t:.3f} s")
 
     # warm-up: the whole menu registered and every class compiled, then a
@@ -341,25 +380,32 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     del engine, feed
     gc.collect()
 
+    # each checked sample against its own family's reference and limit
     checked = pick_checked(records, tr["check_samples"], seed)
     t = time.perf_counter()
     from .reference import Reference, fp8
-    fields = [r.fields for r in checked]
-    want = Reference(cfg, params).samples(fields)
-    if control:
-        served = dict(zip((r.rid for r in checked),
-                          Reference(cfg, params, low=fp8).samples(fields)))
+    checks: Dict[str, dict] = {}
+    wrong, compared = 0, True
+    for fam, view in cell.families.items():
+        mine = [r for r in checked if r.fields["family"] == fam]
+        fields = [r.fields for r in mine]
+        want = Reference(view, params[fam]).samples(fields)
+        got = Reference(view, params[fam], low=fp8).samples(fields) \
+            if control else [served[r.rid] for r in mine]
+        gaps = [rel_l2(x, w) for x, w in zip(got, want)]
+        limit = view["limits"]["sample_rel_l2_max"]
+        wrong += sum(1 for g in gaps if not g <= limit)
+        compared = compared and bool(gaps)
+        name = "sample_rel_l2_max" if len(cell.families) == 1 \
+            else f"sample_rel_l2_max.{fam}"
+        checks[name] = {"value": max(gaps) if gaps else math.inf,
+                        "limit": limit}
     del params
-    gaps = [rel_l2(served[r.rid], w) for r, w in zip(checked, want)]
     log(f"reference: {len(checked)} samples in "
         f"{time.perf_counter() - t:.3f} s")
-    worst = max(gaps) if gaps else math.inf
     unfinished = sum(1 for r in records if r.done is None)
-    limit = cfg["limits"]["sample_rel_l2_max"]
-    wrong = sum(1 for g in gaps if not g <= limit)
-    correct = unfinished == 0 and wrong == 0 and bool(gaps)
-    checks = {"sample_rel_l2_max": {"value": worst, "limit": limit},
-              "unfinished": {"value": unfinished, "limit": 0}}
+    correct = unfinished == 0 and wrong == 0 and compared
+    checks["unfinished"] = {"value": unfinished, "limit": 0}
 
     result = {"correct": correct, "attempted": len(records),
               "failed": unfinished + wrong}

@@ -15,7 +15,8 @@ def read(run):
     seconds, calls = run.trace.kernel(KERNEL)
     if calls == 0 or seconds <= 0:
         return None
-    s = dit_shapes(run.cell.config)
+    # the families of a config share the DiT's widths
+    s = dit_shapes(next(iter(run.cell.families.values())))
     c = flash_attention_cost(run.cell.traffic["slots"], s["tokens"],
                              s["heads"], s["d_head"])
     least, bound = roofline_seconds(c["flops"], c["bytes"], run.peaks)

@@ -4,7 +4,8 @@ and the paper's equations and importing nothing of the program.
 
 What a served request must equal, given its seed and sampler fields:
 
-  prior     u_T = chol(Sigma_T) z,  z = normal(PRNGKey(seed), state_shape)
+  prior     u_T = chol(Sigma_T) V^T z,  z = normal(PRNGKey(seed),
+            state_shape)
   grid      t_j = t_min + (T - t_min) (j/N)^2,  j = 0..N; step k moves
             t_i -> t_{i-1} with i = N - k
   eps       eps_i = DiT(u_i, t_i)   (the configuration's net, float32)
@@ -23,7 +24,14 @@ What a served request must equal, given its seed and sampler fields:
             algorithm 'gmm' adds c sign(s) to it (s from a further
             fold_in(., gmm_salt), c = rho/sqrt(1-rho^2)) and scales chol(P)
             by sqrt(1-rho^2)
-  output    the data channel of u_0
+  output    the data channel of V u_0
+
+The chain runs in the coordinates of the family's orthonormal basis V
+(the state's own entries for VPSDE and CLD): u above is V^T of the state,
+the DiT sees V u and its eps is carried back by V^T, and the noise n is
+V^T of the state-space draw.  Each coefficient is a (k, k) block, the
+same at every coordinate or one per coordinate (`families/__init__.py`);
+a configuration's `sde.kind` names its family file.
 
 Coefficients are computed here in float64 by Gauss-Legendre quadrature and
 adaptive ODE solves (scipy), independently of the program's Stage-I code.
@@ -36,99 +44,24 @@ from typing import Dict, List
 
 import numpy as np
 import scipy.integrate
-import scipy.linalg
+
+from chipbench import families
 
 GL_POINTS = 64
 ODE_TOL = dict(rtol=1e-10, atol=1e-13)
 
 
-# ---------------------------------------------------------------------------
-# the linear SDEs, every coefficient a (k, k) float64 matrix
-# ---------------------------------------------------------------------------
-class VPSDE:
-    k = 1
-
-    def __init__(self, beta0: float, beta1: float, T: float, t_min: float):
-        self.b0, self.b1, self.T, self.t_min = beta0, beta1, T, t_min
-
-    def alpha(self, t):
-        return math.exp(-(self.b0 * t + 0.5 * (self.b1 - self.b0) * t * t))
-
-    def beta(self, t):
-        return self.b0 + (self.b1 - self.b0) * t
-
-    def F(self, t):
-        return np.array([[-0.5 * self.beta(t)]])
-
-    def G2(self, t):
-        return np.array([[self.beta(t)]])
-
-    def Sigma(self, t):
-        return np.array([[1.0 - self.alpha(t)]])
-
-    def Psi(self, t, s):
-        return np.array([[math.sqrt(self.alpha(t) / self.alpha(s))]])
-
-    def K(self, t):
-        return np.array([[math.sqrt(1.0 - self.alpha(t))]])
+def _T(a: np.ndarray) -> np.ndarray:
+    """Each (k, k) block transposed."""
+    return np.swapaxes(a, -1, -2)
 
 
-class CLD:
-    """Critically-damped Langevin diffusion (Dockhorn et al.), state (x, v):
-    F = beta [[0, M^-1], [-1, -Gamma M^-1]], G2 = diag(0, 2 Gamma beta),
-    Sigma_0 = diag(0, gamma M).  K = R solves Eq. 17,
-    dR/dt = (F + 1/2 G2 Sigma^-1) R, from the symmetric square root of
-    Sigma at t = r_anchor."""
-    k = 2
-
-    def __init__(self, beta: float, M_inv: float, Gamma: float, gamma: float,
-                 T: float, t_min: float, r_anchor: float):
-        self.T, self.t_min, self.r_anchor = T, t_min, r_anchor
-        self.A = beta * np.array([[0.0, M_inv], [-1.0, -Gamma * M_inv]])
-        self.Q = np.array([[0.0, 0.0], [0.0, 2.0 * Gamma * beta]])
-        self.S0 = np.array([[0.0, 0.0], [0.0, gamma / M_inv]])
-
-    def F(self, t):
-        return self.A
-
-    def G2(self, t):
-        return self.Q
-
-    def Sigma(self, t):
-        # Van Loan: expm(t [[A, Q], [0, -A^T]]) = [[E, E V], [0, E^-T]],
-        # V = int_0^t e^{-As} Q e^{-A^T s} ds, Sigma = E S0 E^T + E V E^T
-        M = np.zeros((4, 4))
-        M[:2, :2], M[:2, 2:], M[2:, 2:] = self.A, self.Q, -self.A.T
-        X = scipy.linalg.expm(M * t)
-        E, EV = X[:2, :2], X[:2, 2:]
-        return E @ self.S0 @ E.T + EV @ E.T
-
-    def Psi(self, t, s):
-        return scipy.linalg.expm(self.A * (t - s))
-
-    @functools.cached_property
-    def _R(self):
-        t0 = self.r_anchor
-        w, v = np.linalg.eigh(self.Sigma(t0))
-        R0 = (v * np.sqrt(np.clip(w, 0.0, None))) @ v.T
-
-        def rhs(t, y):
-            R = y.reshape(2, 2)
-            return ((self.A + 0.5 * self.Q @ np.linalg.inv(self.Sigma(t)))
-                    @ R).ravel()
-
-        sol = scipy.integrate.solve_ivp(rhs, (t0, self.T), R0.ravel(),
-                                        method="Radau", dense_output=True,
-                                        **ODE_TOL)
-        return sol.sol
-
-    def K(self, t):
-        return self._R(t).reshape(2, 2)
-
-
-def make_sde(sde_cfg: dict):
-    kw = {k: v for k, v in sde_cfg.items() if k != "kind"}
-    return {"vpsde": VPSDE, "cld": CLD}[sde_cfg["kind"]](**kw)
+def apply(c: np.ndarray, z: np.ndarray) -> np.ndarray:
+    """A coefficient, (k, k) or one (k, k) block per coordinate (D, k, k),
+    on states z (b, k, D)."""
+    if c.ndim == 2:
+        return np.einsum("ij,bjd->bid", c, z)
+    return np.einsum("dij,bjd->bid", c, z)
 
 
 # ---------------------------------------------------------------------------
@@ -158,11 +91,11 @@ def _ei_integral(sde, t_from: float, t_to: float, weight) -> np.ndarray:
     half = 0.5 * (t_to - t_from)
     s = t_from + half * (x + 1.0)
     ws = weight(s)
-    acc = np.zeros((sde.k, sde.k))
+    acc = 0.0
     for si, wi, li in zip(s, w, ws):
         core = 0.5 * sde.Psi(t_to, si) @ sde.G2(si) \
             @ np.linalg.solve(sde.Sigma(si), sde.K(si))
-        acc += wi * li * core
+        acc = acc + wi * li * core
     return half * acc
 
 
@@ -172,19 +105,22 @@ def _stochastic(sde, t_i: float, t_im1: float, lam: float):
         return sde.F(s) + 0.5 * (1.0 + lam * lam) * sde.G2(s) \
             @ np.linalg.inv(sde.Sigma(s))
 
-    k = sde.k
+    shape = np.shape(F_hat(t_i))            # (k, k) or (D, k, k)
     ph = scipy.integrate.solve_ivp(
-        lambda s, y: (F_hat(s) @ y.reshape(k, k)).ravel(), (t_i, t_im1),
-        np.eye(k).ravel(), method="DOP853", **ODE_TOL).y[:, -1]
+        lambda s, y: (F_hat(s) @ y.reshape(shape)).ravel(), (t_i, t_im1),
+        np.broadcast_to(np.eye(sde.k), shape).ravel(), method="DOP853",
+        **ODE_TOL).y[:, -1]
 
     def p_rhs(sig, y):
-        P, fh = y.reshape(k, k), F_hat(t_i - sig)
-        return (-(fh @ P + P @ fh.T) + lam * lam * sde.G2(t_i - sig)).ravel()
+        P, fh = y.reshape(shape), F_hat(t_i - sig)
+        return (-(fh @ P + P @ _T(fh))
+                + lam * lam * sde.G2(t_i - sig)).ravel()
 
     P = scipy.integrate.solve_ivp(p_rhs, (0.0, t_i - t_im1),
-                                  np.zeros(k * k), method="DOP853",
-                                  **ODE_TOL).y[:, -1].reshape(k, k)
-    return ph.reshape(k, k), 0.5 * (P + P.T)
+                                  np.zeros(int(np.prod(shape))),
+                                  method="DOP853",
+                                  **ODE_TOL).y[:, -1].reshape(shape)
+    return ph.reshape(shape), 0.5 * (P + _T(P))
 
 
 def sampler_coeffs(sde, fields: dict, sampler: dict) -> dict:
@@ -214,7 +150,8 @@ def sampler_coeffs(sde, fields: dict, sampler: dict) -> dict:
             st["B"] = (ph - st["psi"]) @ sde.K(t_i)
             # P is PSD; lift round-off negativity before the Cholesky
             w, v = np.linalg.eigh(P)
-            P = (v * np.maximum(w, 1e-14 * abs(np.trace(P)))) @ v.T
+            floor = 1e-14 * np.abs(np.trace(P, axis1=-2, axis2=-1))
+            P = (v * np.maximum(w, floor[..., None])[..., None, :]) @ _T(v)
             L = np.linalg.cholesky(P)
             if alg == "gmm":
                 L = L * math.sqrt(1.0 - sampler["gmm_rho"] ** 2)
@@ -307,18 +244,19 @@ def dit_forward(p, u, t, cfg: dict, low=None):
 # the chain
 # ---------------------------------------------------------------------------
 class Reference:
-    """Reference samples for a configuration, in chunks of `chunk` rows
-    (one compiled float32 forward per configuration).  `low=fp8` makes
-    it the correctness check's control: the same chain with every matmul
-    input in fp8."""
+    """Reference samples for one family of a configuration (the
+    configuration as that family sees it), in chunks of `chunk` rows (one
+    compiled float32 forward per family).  `low=fp8` makes it the
+    correctness check's control: the same chain with every matmul input
+    in fp8."""
 
     def __init__(self, cfg: dict, params, chunk: int = 8, low=None):
         import jax
         import jax.numpy as jnp
         self.cfg, self.chunk = cfg, chunk
-        self.sde = make_sde(cfg["sde"])
+        self.sde = families.make(cfg["sde"], cfg["data_shape"])
         self.sampler = cfg["sampler"]
-        self.state_shape = ((2,) if self.sde.k == 2 else ()) \
+        self.state_shape = ((self.sde.k,) if self.sde.k > 1 else ()) \
             + tuple(cfg["data_shape"])
         self.D = int(np.prod(cfg["data_shape"]))
         self.p32 = jax.tree.map(lambda w: w.astype(jnp.float32), params)
@@ -326,15 +264,17 @@ class Reference:
         self._coeffs: Dict[tuple, dict] = {}
 
     def _eps(self, u: np.ndarray, t: np.ndarray) -> np.ndarray:
-        """u (b, k, D) float64 -> eps (b, k, D) float64, padded to chunk."""
+        """u (b, k, D) in the basis, float64 -> eps (b, k, D) in the
+        basis, float64; the net runs in state space, padded to chunk."""
         import jax.numpy as jnp
         b = u.shape[0]
         x = np.zeros((self.chunk,) + self.state_shape, np.float32)
-        x[:b] = u.reshape((b,) + self.state_shape)
+        x[:b] = self.sde.from_basis(u).reshape((b,) + self.state_shape)
         tt = np.full((self.chunk,), t[0], np.float32)
         tt[:b] = t
         e = self._fwd(self.p32, jnp.asarray(x), jnp.asarray(tt))
-        return np.asarray(e, np.float64)[:b].reshape(u.shape)
+        return self.sde.to_basis(
+            np.asarray(e, np.float64)[:b].reshape(u.shape))
 
     def _noise(self, seeds, alg: str, k: int) -> np.ndarray:
         import jax
@@ -353,7 +293,7 @@ class Reference:
                 c = np.float32(s["gmm_rho"] / math.sqrt(1 - s["gmm_rho"] ** 2))
                 z = z + c * jnp.where(sn >= 0, 1.0, -1.0).astype(jnp.float32)
             out.append(np.asarray(z, np.float64).reshape(self.sde.k, self.D))
-        return np.stack(out)
+        return self.sde.to_basis(np.stack(out))
 
     def _prior(self, seeds, chol_T) -> np.ndarray:
         import jax
@@ -361,7 +301,7 @@ class Reference:
         z = np.stack([np.asarray(jax.random.normal(
             jax.random.PRNGKey(s), (1,) + self.state_shape, jnp.float32),
             np.float64).reshape(self.sde.k, self.D) for s in seeds])
-        return np.einsum("ij,bjd->bid", chol_T, z)
+        return apply(chol_T, self.sde.to_basis(z))
 
     def samples(self, requests: List[dict]) -> List[np.ndarray]:
         """requests: sampler fields + seed each; returns data-space samples
@@ -386,7 +326,6 @@ class Reference:
         return out
 
     def _chain(self, co: dict, reqs: List[dict]) -> List[np.ndarray]:
-        ap = lambda c, z: np.einsum("ij,bjd->bid", c, z)
         seeds = [r["seed"] for r in reqs]
         alg, b = reqs[0]["algorithm"], len(reqs)
         u = self._prior(seeds, co["chol_T"])
@@ -394,19 +333,20 @@ class Reference:
         for k, st in enumerate(co["steps"]):
             eps = self._eps(u, np.full((b,), st["t"]))
             hist = [eps] + hist
-            u_lin = ap(st["psi"], u)
+            u_lin = apply(st["psi"], u)
             u_pred = u_lin
             for c, e in zip(st["pC"], hist):
-                u_pred = u_pred + ap(c, e)
+                u_pred = u_pred + apply(c, e)
             if "B" in st:
-                u = u_lin + ap(st["B"], eps) \
-                    + ap(st["P_chol"], self._noise(seeds, alg, k))
+                u = u_lin + apply(st["B"], eps) \
+                    + apply(st["P_chol"], self._noise(seeds, alg, k))
             elif "cC" in st:
                 eps_n = self._eps(u_pred, np.full((b,), st["t_next"]))
-                u = u_lin + ap(st["cC"][0], eps_n)
+                u = u_lin + apply(st["cC"][0], eps_n)
                 for c, e in zip(st["cC"][1:], hist):
-                    u = u + ap(c, e)
+                    u = u + apply(c, e)
             else:
                 u = u_pred
             hist = hist[:co["q"]]
-        return [x[0].reshape(self.cfg["data_shape"]) for x in u]
+        return [x[0].reshape(self.cfg["data_shape"])
+                for x in self.sde.from_basis(u)]

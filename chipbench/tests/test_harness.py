@@ -1,7 +1,8 @@
 """Whole runs of tiny cells on the CPU (the look for a chip skipped): the
 sound program is correct, and each fault a serving cell can have under
-the timed path makes `correct` false.  The exchange between chips has no
-fault here: every cell runs on one chip."""
+the timed path makes `correct` false, with one family and with two
+co-resident ones.  The exchange between chips has no fault here: every
+cell runs on one chip."""
 import os
 import shutil
 import subprocess
@@ -11,7 +12,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench.harness import run_cell
+from chipbench.harness import Abort, family_views, run_cell
 from chipbench.tests import tiny
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -72,6 +73,62 @@ def test_fault_makes_the_run_incorrect(fault):
     res = run(tiny.cell("vpsde"), engine_hook=fault)
     assert not res["correct"]
     assert res["checks"]["sample_rel_l2_max"]["value"] > 1e-1
+
+
+TWO = ("sample_rel_l2_max.vpsde", "sample_rel_l2_max.cld")
+
+
+def test_two_family_run_is_correct():
+    """VPSDE and CLD co-resident, mixed traffic: each family's samples are
+    held to its own reference and limit."""
+    res = run(tiny.cell("vpsde+cld"))
+    assert res["correct"], res
+    assert res["failed"] == 0 and res["attempted"] == 12
+    assert list(res["checks"]) == [*TWO, "unfinished"]
+    for name in TWO:
+        assert res["checks"][name]["value"] < 1e-5
+
+
+@pytest.mark.parametrize("fault", [_unchanged_state, _half_batch,
+                                   _neighbour_answer])
+def test_fault_makes_the_two_family_run_incorrect(fault):
+    res = run(tiny.cell("vpsde+cld"), engine_hook=fault)
+    assert not res["correct"]
+    assert max(res["checks"][n]["value"] for n in TWO) > 1e-1
+
+
+def test_a_single_family_config_is_its_own_view():
+    cfg = tiny.config("cld")
+    views = family_views(cfg)
+    assert list(views) == ["cld"] and views["cld"] is cfg
+    cell = tiny.cell("cld")
+    assert {m["family"] for m in cell.traffic["mix"]} == {"cld"}
+
+
+def test_cell_gives_each_family_its_own_view():
+    cell = tiny.cell("vpsde+cld")
+    v, c = cell.families["vpsde"], cell.families["cld"]
+    assert (v["in_channels"], c["in_channels"]) == (3, 6)
+    assert v["sde"]["kind"] == "vpsde" and c["sde"]["kind"] == "cld"
+    assert v["hidden_size"] == c["hidden_size"] == 64
+    assert "families" not in v
+    with pytest.raises(Abort, match="listed twice"):
+        family_views(tiny.co_resident(tiny.config("cld"),
+                                      tiny.config("cld")))
+    with pytest.raises(Abort, match="bdm"):
+        tiny.cell("vpsde", mix=[dict(tiny.MIX[0], family="bdm")])
+
+
+def test_each_family_draws_its_own_net():
+    """The first family draws from the seed's own key, as a single-family
+    config does; the next from a key of its own."""
+    import numpy as np
+    from chipbench.weights import make_weights
+    cfg = tiny.config("vpsde")
+    w0, again, w1 = (make_weights(cfg, 2**31 + 9, family_index=j)
+                     for j in (0, 0, 1))
+    assert np.array_equal(w0["t_mlp2"], again["t_mlp2"])
+    assert not np.array_equal(w0["t_mlp2"], w1["t_mlp2"])
 
 
 @pytest.mark.parametrize("family", ["vpsde", "cld"])

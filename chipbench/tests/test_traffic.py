@@ -3,6 +3,8 @@ seed draws only each request's own seed."""
 import json
 from pathlib import Path
 
+import pytest
+
 from chipbench import traffic
 
 TRAFFIC = Path(__file__).resolve().parents[1] / "traffic"
@@ -62,6 +64,19 @@ def test_percentile_interpolates_like_numpy():
     xs = [5.0, 1.0, 4.0, 2.0, 3.0, 10.0]
     for p in (50.0, 95.0):
         assert traffic.percentile(xs, p) == np.percentile(xs, p)
+
+
+def test_mix_entries_may_name_a_family(tmp_path):
+    t = {"kind": "backlog", "slots": 4, "check_samples": 2,
+         "mix": [entry(10, 1, family="cld"), entry(10, 1)]}
+    path = tmp_path / "two.json"
+    path.write_text(json.dumps(t))
+    assert [m.get("family") for m in traffic.load_traffic(path)["mix"]] \
+        == ["cld", None]
+    assert traffic.menu(t)[0]["family"] == "cld"
+    path.write_text(json.dumps(dict(t, mix=[entry(10, 1, model="dit")])))
+    with pytest.raises(ValueError, match="mix entry keys"):
+        traffic.load_traffic(path)
 
 
 def test_traffic_files_parse():

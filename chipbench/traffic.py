@@ -10,7 +10,9 @@ A traffic mix is a data file (`traffic/<name>.json`) read by
                 "poisson": open-loop arrivals at `rate_per_s`
   slots         the engine's batch size B
   rate_per_s    poisson only: the offered rate
-  mix           list of {weight, nfe, q, corrector, lam, algorithm}
+  mix           list of {weight, nfe, q, corrector, lam, algorithm} and,
+                optionally, `family`: the SDE family of a config that
+                holds several (the config's first where it is left out)
   check_samples how many served requests the correctness check compares
 
 Every seed gives the same work in the same order: which mix entry each
@@ -31,6 +33,7 @@ import numpy as np
 KINDS = ("backlog", "poisson")
 ORDER_SEED = 0          # orders the mix entries and the gaps of every run
 MIX_KEYS = ("weight", "nfe", "q", "corrector", "lam", "algorithm")
+OPTIONAL_MIX_KEYS = ("family",)
 
 
 def load_traffic(path: Path) -> dict:
@@ -40,8 +43,9 @@ def load_traffic(path: Path) -> dict:
     if t["kind"] == "poisson" and not t.get("rate_per_s", 0) > 0:
         raise ValueError(f"{path}: a poisson mix needs rate_per_s > 0")
     for m in t["mix"]:
-        if set(m) != set(MIX_KEYS):
-            raise ValueError(f"{path}: mix entry keys must be {MIX_KEYS}")
+        if not set(MIX_KEYS) <= set(m) <= set(MIX_KEYS + OPTIONAL_MIX_KEYS):
+            raise ValueError(f"{path}: mix entry keys must be {MIX_KEYS}, "
+                             f"optionally with {OPTIONAL_MIX_KEYS}")
     return t
 
 
@@ -101,7 +105,7 @@ class Plan:
 
 def menu(traffic: dict) -> List[dict]:
     """The distinct sampler configs of a mix (warmed before the window)."""
-    return [{k: m[k] for k in MIX_KEYS if k != "weight"}
+    return [{k: v for k, v in m.items() if k != "weight"}
             for m in traffic["mix"]]
 
 

@@ -51,9 +51,13 @@ def dit_tree(key, cfg: dict):
     }
 
 
-def make_weights(cfg: dict, seed: int):
-    """One jitted draw on the default device; the seed may exceed 32 bits."""
+def make_weights(cfg: dict, seed: int, family_index: int = 0):
+    """One jitted draw on the default device; the seed may exceed 32 bits.
+    Each family of a config (`family_index`, in the file's order) draws
+    its own net; the first draws from the seed's own key."""
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
                              seed >> 31)
+    if family_index:
+        key = jax.random.fold_in(key, family_index)
     init = jax.jit(functools.partial(dit_tree, cfg=cfg))
     return jax.block_until_ready(init(key))
